@@ -1499,41 +1499,52 @@ class Simulator:
         if n <= 0:
             return {}
         start = self.round_idx
-        if key is None:
-            keys = jax.vmap(lambda i: jax.random.fold_in(self.base_key, i))(
-                start + jnp.arange(n))
-        else:
-            keys = jax.random.split(key, n)
         if self._host_mode:
-            return self._run_host(n, keys)
-        if self._emit is not None:
-            self._emit.reset()
-        rs = start + jnp.arange(1, n + 1, dtype=jnp.int32)
-        if self.fl.staleness >= 2:
-            if self._ring is None:
-                self._ring = self._zero_ring()
-            ring, rvalid, pos = self._ring
-            params, state, ring, rvalid, pos, diags = self._scan_pipe_jit(
-                self.params, self._get_state(), ring, rvalid, pos, keys, rs)
-            self._ring = (ring, rvalid, pos)
-        elif self.fl.staleness:
-            if self._pending is None:
-                self._pending = self._zero_pending()
-            params, state, pending, valid, diags = self._scan_async_jit(
-                self.params, self._get_state(), self._pending, self._valid,
-                keys, rs)
-            self._pending, self._valid = pending, valid
-        else:
-            params, state, diags = self._scan_jit(
-                self.params, self._get_state(), keys, rs)
-        self.round_idx += n
-        self.params = params
-        self._set_state(state)
-        if self._emit is not None:
-            # every per-round callback has run before we hand back control
-            # (io_callback is ordered but asynchronous w.r.t. the host)
-            jax.effects_barrier()
-        return {k: np.asarray(v) for k, v in diags.items()}
+            return self._run_host(n, self._round_keys(start, n, key))
+        # host spans (track.HOST_SPANS) on the profiler's clock: a device
+        # idle stretch between two scans lands in the phase that held it
+        with track.span(track.DRAW_BATCH, round=start):
+            keys = self._round_keys(start, n, key)
+            rs = start + jnp.arange(1, n + 1, dtype=jnp.int32)
+        with track.span(track.DISPATCH, round=start, rounds=n):
+            if self._emit is not None:
+                self._emit.reset()
+            if self.fl.staleness >= 2:
+                if self._ring is None:
+                    self._ring = self._zero_ring()
+                ring, rvalid, pos = self._ring
+                params, state, ring, rvalid, pos, diags = \
+                    self._scan_pipe_jit(self.params, self._get_state(), ring,
+                                        rvalid, pos, keys, rs)
+                self._ring = (ring, rvalid, pos)
+            elif self.fl.staleness:
+                if self._pending is None:
+                    self._pending = self._zero_pending()
+                params, state, pending, valid, diags = self._scan_async_jit(
+                    self.params, self._get_state(), self._pending,
+                    self._valid, keys, rs)
+                self._pending, self._valid = pending, valid
+            else:
+                params, state, diags = self._scan_jit(
+                    self.params, self._get_state(), keys, rs)
+            self.round_idx += n
+            self.params = params
+            self._set_state(state)
+        with track.span(track.BLOCK, round=start, arrays=len(diags)):
+            if self._emit is not None:
+                # every per-round callback has run before we hand back
+                # control (io_callback is ordered but asynchronous w.r.t.
+                # the host)
+                jax.effects_barrier()
+            return {k: np.asarray(v) for k, v in diags.items()}
+
+    def _round_keys(self, start, n, key):
+        """The n round keys of a call: fold_in(base_key, round index), or
+        n splits of an explicit key."""
+        if key is None:
+            return jax.vmap(lambda i: jax.random.fold_in(self.base_key, i))(
+                start + jnp.arange(n))
+        return jax.random.split(key, n)
 
     # ------------------------------------------------------------------
     # evaluation: one padded, vmapped pass over all clients

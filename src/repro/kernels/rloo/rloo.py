@@ -151,6 +151,7 @@ def rloo_combine(g_stack, alpha, *, block_n: int = 512,
             acc_shape,
         ],
         interpret=interpret,
+        name="rloo_combine",
     )(g_padded, alpha_arr)
     mean = mean[0]
     if pad:
@@ -229,6 +230,7 @@ def ncv_weighted_sum(g_flat, w, *, block_n: int = 512,
         out_specs=[agg_spec, acc_spec],
         out_shape=[agg_shape, acc_shape],
         interpret=interpret,
+        name="ncv_weighted_sum",
     )(g_padded, jnp.reshape(w, (m, 1)))
     agg = agg[0, :n]
     return agg, jnp.sum(nrm_parts)
@@ -263,11 +265,12 @@ def scale_column(s_ref):
     return jnp.sum(jnp.where(hit, s, 0.0), axis=1, keepdims=True)
 
 
-def _dequant_weighted_sum(kernel, stack, scales, w, chunk, interpret):
-    """Shared pallas_call of the two wire kernels: grid over the C chunks,
-    one (M, stack_cols / C) wire tile per step, the scale table zero-padded
-    to a multiple of 128 columns (M * C floats — negligible next to the
-    stack) so every (M, 128) scale block is in bounds."""
+def _dequant_weighted_sum(kernel, name, stack, scales, w, chunk, interpret):
+    """Shared pallas_call of the two wire kernels, named `name` in the
+    trace: grid over the C chunks, one (M, stack_cols / C) wire tile per
+    step, the scale table zero-padded to a multiple of 128 columns (M * C
+    floats — negligible next to the stack) so every (M, 128) scale block
+    is in bounds."""
     if interpret is None:
         interpret = default_interpret()
     m, c = scales.shape
@@ -286,6 +289,7 @@ def _dequant_weighted_sum(kernel, stack, scales, w, chunk, interpret):
         out_specs=[agg_spec, acc_spec],
         out_shape=[agg_shape, acc_shape],
         interpret=interpret,
+        name=name,
     )(stack, table, jnp.reshape(jnp.asarray(w, jnp.float32), (m, 1)))
     return agg[0], jnp.sum(nrm_parts)
 
@@ -320,8 +324,8 @@ def ncv_weighted_sum_q(q, scales, w, *, chunk: int = 512,
     c = n_packed // chunk
     assert n_packed == c * chunk, (n_packed, chunk)
     assert scales.shape == (m, c), (scales.shape, (m, c))
-    return _dequant_weighted_sum(_ncv_agg_q_kernel, q, scales, w, chunk,
-                                 interpret)
+    return _dequant_weighted_sum(_ncv_agg_q_kernel, "ncv_weighted_sum_q", q,
+                                 scales, w, chunk, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -368,8 +372,8 @@ def ncv_weighted_sum_q4(qp, scales, w, *, chunk: int = 512,
     assert chunk % 2 == 0, chunk
     assert n_packed == c * chunk, (n_packed, chunk)
     assert scales.shape == (m, c), (scales.shape, (m, c))
-    return _dequant_weighted_sum(_ncv_agg_q4_kernel, qp, scales, w, chunk,
-                                 interpret)
+    return _dequant_weighted_sum(_ncv_agg_q4_kernel, "ncv_weighted_sum_q4",
+                                 qp, scales, w, chunk, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

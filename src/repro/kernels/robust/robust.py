@@ -105,5 +105,6 @@ def rank_band_mean(g_flat, alive, lo, hi, *, block_n: int = 512,
         out_specs=[agg_spec, acc_spec],
         out_shape=[agg_shape, acc_shape],
         interpret=interpret,
+        name="rank_band_mean",
     )(g_padded, jnp.reshape(alive, (m, 1)), alive, band)
     return agg[0, :n], jnp.sum(nrm_parts)

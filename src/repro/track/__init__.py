@@ -1,7 +1,8 @@
 from repro.track.trackers import (  # noqa: F401
-    AGGREGATE, CLIENT_PASS, ENCODE, GNORM_KEY, PHASES, SERVER_UPDATE,
-    CompositeTracker, CsvTracker, JsonlTracker, MemoryTracker, NullTracker,
-    StdoutTracker, Tracker, TrackerSpec, composite, emitter, get_tracker,
-    make_tracker, register_tracker, registered_trackers, resolve_opts,
-    scope, tether, with_grad_stats,
+    AGGREGATE, BLOCK, CLIENT_PASS, DISPATCH, DRAW_BATCH, ENCODE, GNORM_KEY,
+    HOST_SPANS, PHASES, SERVER_UPDATE, CompositeTracker, CsvTracker,
+    JsonlTracker, MemoryTracker, NullTracker, StdoutTracker, Tracker,
+    TrackerSpec, composite, emitter, get_tracker, make_tracker,
+    register_tracker, registered_trackers, resolve_opts, scope, span, tether,
+    with_grad_stats,
 )

@@ -48,6 +48,22 @@ vocabulary (``client_pass`` / ``encode`` / ``aggregate`` /
 ``server_update``) so `launch/dryrun.py` profiles and `jax.profiler` traces
 map operators back to round phases.  Named scopes attach HLO metadata only
 — they never change the computation.
+
+Host spans: `span(name, **ids)` wraps `jax.profiler.TraceAnnotation`, so
+the host phases of one dispatch land in a profiler trace on the same clock
+as the device's ops, and an idle stretch of the device can be laid on what
+the host was doing.  The vocabulary (``HOST_SPANS``):
+
+* ``draw_batch`` — building the call's inputs on the host (round keys,
+  round indices);
+* ``dispatch``   — assembling the arguments and enqueueing the jitted call;
+* ``block``      — waiting for the call's outputs and copying them to the
+  host.
+
+Each span carries integer ids as trace stats (``round``, the call's first
+round index, on all three), so the spans of one call share an identifier.
+With no profiler running a span is an inert C++ TraceMe (under a
+microsecond), so the spans are always on.
 """
 from __future__ import annotations
 
@@ -76,6 +92,19 @@ def scope(name: str):
     """A profiler phase scope (`jax.named_scope`): free at runtime, tags the
     enclosed ops' HLO metadata so traces map back to round phases."""
     return jax.named_scope(name)
+
+
+DRAW_BATCH = "draw_batch"
+DISPATCH = "dispatch"
+BLOCK = "block"
+HOST_SPANS = (DRAW_BATCH, DISPATCH, BLOCK)
+
+
+def span(name: str, **ids: int):
+    """A host span (`jax.profiler.TraceAnnotation`) named from HOST_SPANS,
+    with `ids` as its trace stats: marks host time on the profiler's clock
+    and never touches a traced computation."""
+    return jax.profiler.TraceAnnotation(name, **ids)
 
 
 # Reserved aux key: with `FLConfig.track_variance` the client pass is

@@ -1,4 +1,5 @@
-"""Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e.
+"""Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e,
+and the names they carry into a trace.
 
 The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached (`jax.experimental.topologies`), so these tests
@@ -13,6 +14,7 @@ every worker imports this file.
 """
 import math
 import os
+import re
 import sys
 
 import jax
@@ -110,6 +112,20 @@ KERNELS = ["rloo_combine", "ncv_weighted_sum", "ncv_weighted_sum_q",
            "ncv_weighted_sum_q4", "rank_band_mean"]
 CASES = [(k, w) for w in WIDTHS for k in KERNELS] + \
     [("rloo_combine_vmap", "lenet5")]
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_kernel_lowers_for_tpu_under_its_public_name(kernel):
+    """A trace names a Mosaic kernel by its `kernel_name`: each main-path
+    kernel carries its public op's name, not its private body's.  A
+    lowering for the TPU needs no TPU library, so no fixture."""
+    fn, args = kernel_case(
+        kernel, "lenet5",
+        lambda s, dt=jnp.float32: jax.ShapeDtypeStruct(s, dt))
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    assert re.findall(r'kernel_name = "([^"]*)"', text) == [kernel]
 
 
 @pytest.mark.parametrize("kernel,width", CASES)

@@ -193,6 +193,57 @@ def test_none_bit_identical_mesh(tiny_setup):
     assert [r["round"] for r in rows] == [1, 2]
 
 
+# ------------------------ host spans -----------------------------------------
+
+def _host_spans(trace_dir):
+    """[(start_ns, end_ns, name, ids)] of the HOST_SPANS in the profile
+    written under trace_dir, in time order."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = ProfileData.from_file(path)
+    return sorted((int(ev.start_ns), int(ev.start_ns + ev.duration_ns),
+                   ev.name, dict(ev.stats))
+                  for plane in pd.planes if plane.name.startswith("/host:")
+                  for line in plane.lines for ev in line.events
+                  if ev.name in track.HOST_SPANS)
+
+
+def test_span_is_a_trace_annotation():
+    with track.span(track.DISPATCH, round=3, rounds=2) as s:
+        assert isinstance(s, jax.profiler.TraceAnnotation)
+    assert track.HOST_SPANS == ("draw_batch", "dispatch", "block")
+
+
+@pytest.mark.parametrize("staleness,tracker", [
+    (0, "none"), (1, "none"), (2, "none"), (0, "memory")])
+def test_run_rounds_writes_host_spans(tiny_setup, tmp_path, staleness,
+                                      tracker):
+    """Each call writes draw_batch, dispatch, block once, in that order and
+    without overlap, each with the call's first round as `round`; under a
+    profiler the trajectory and diagnostics equal an unprofiled run's."""
+    plain = _sim(tiny_setup, staleness=staleness, tracker=tracker)
+    sim = _sim(tiny_setup, staleness=staleness, tracker=tracker)
+    calls = [(0, 2), (2, 2)]
+    want = [plain.run_rounds(n) for _, n in calls]
+    with jax.profiler.trace(str(tmp_path)):
+        got = [sim.run_rounds(n) for _, n in calls]
+    for a, b in zip(want, got):
+        _same(a, b)
+    for x, y in zip(jax.tree.leaves(plain.params),
+                    jax.tree.leaves(sim.params)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    spans = _host_spans(str(tmp_path))
+    assert [s[2] for s in spans] == list(track.HOST_SPANS) * len(calls)
+    assert all(a[1] <= b[0] for a, b in zip(spans, spans[1:]))
+    for i, (start, n) in enumerate(calls):
+        draw, disp, block = (s[3] for s in spans[3 * i:3 * i + 3])
+        assert draw == {"round": start}
+        assert disp == {"round": start, "rounds": n}
+        assert block == {"round": start, "arrays": len(got[i])}
+
+
 # ------------------------ in-scan streaming ----------------------------------
 
 class _FileCountProbe(track.Tracker):
