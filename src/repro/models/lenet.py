@@ -54,9 +54,50 @@ def _conv(x, w, b):
     return out + b
 
 
+def _corners(x):
+    """The 2x2 stride-2 windows of (B, H, W, C) as four (B, H//2, W//2, C)
+    arrays, in row-major window order (0,0), (0,1), (1,0), (1,1); an odd
+    last row or column is dropped, as VALID pooling drops it."""
+    b, hh, ww, c = x.shape
+    h, w = hh // 2, ww // 2
+    win = x[:, :2 * h, :2 * w].reshape(b, h, 2, w, 2, c)
+    return [win[:, :, i, :, j] for i in (0, 1) for j in (0, 1)]
+
+
+@jax.custom_vjp
 def _pool(x):
-    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
-                                 (1, 2, 2, 1), "VALID")
+    """2x2 stride-2 VALID max-pool over (B, H, W, C), as elementwise ops
+    that XLA fuses and lays out freely (`lax.reduce_window` and its
+    `select_and_scatter_add` gradient are layout-bound on the TPU).  The
+    gradient is `reduce_window`'s bit for bit: the whole cotangent goes to
+    the first maximum of each window in window order, where the gradient
+    of `jnp.max` would split it over ties."""
+    q = _corners(x)
+    return jnp.maximum(jnp.maximum(q[0], q[1]), jnp.maximum(q[2], q[3]))
+
+
+def _pool_fwd(x):
+    y = _pool(x)
+    return y, (x, y)
+
+
+def _pool_bwd(res, g):
+    x, y = res
+    q = _corners(x)
+    # window order of each window's first maximum
+    first = jnp.where(q[0] == y, 0, jnp.where(
+        q[1] == y, 1, jnp.where(q[2] == y, 2, 3))).astype(jnp.int8)
+    # and of each element of the (B, h, 2, w, 2, C) view: 2 * row + column
+    order = jnp.arange(4, dtype=jnp.int8).reshape(2, 1, 2, 1)
+    dx = jnp.where(order == first[:, :, None, :, None],
+                   g[:, :, None, :, None], jnp.zeros((), g.dtype))
+    b, h, _, w, _, c = dx.shape
+    dx = dx.reshape(b, 2 * h, 2 * w, c)
+    return (jnp.pad(dx, ((0, 0), (0, x.shape[1] - 2 * h),
+                         (0, x.shape[2] - 2 * w), (0, 0))),)
+
+
+_pool.defvjp(_pool_fwd, _pool_bwd)
 
 
 def forward(cfg: LeNetConfig, params, images):

@@ -159,3 +159,32 @@ def test_client_pass_kernel_compiles_under_model_axis(topo):
             jax.ShapeDtypeStruct((2,), jnp.float32,
                                  sharding=NamedSharding(mesh, P("cohort"))))
     assert "tpu_custom_call" in compiled_text(fn, *args)
+
+
+def test_lenet_microbatch_grads_compile_without_window_ops(one_chip):
+    """The paper protocol's client pass, the per-microbatch LeNet-5
+    gradients vmapped over the cohort (10 clients, K=4 microbatches of 16
+    cifar10 images), compiles for the v5e with its max-pools as fused
+    elementwise ops: no `reduce-window` forward, no `select-and-scatter`
+    backward (both are layout-bound on the TPU)."""
+    from repro.fed.methods import Task, _microbatch_grads
+    from repro.models import lenet
+    cfg = lenet.LeNetConfig(n_classes=10, image_size=32, channels=3)
+    task = Task(loss=lambda p, b: lenet.loss_fn(cfg, p, b))
+    k, m = WIDTHS["lenet5"]
+
+    def sds(s, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape),
+        jax.eval_shape(lambda: lenet.init(cfg, jax.random.key(0))))
+    batches = {"images": sds((m, k, 16, 32, 32, 3)),
+               "labels": sds((m, k, 16), jnp.int32)}
+    with jax.default_matmul_precision("default"):
+        text = compiled_text(
+            jax.vmap(lambda p, b: _microbatch_grads(task, p, b), (None, 0)),
+            params, batches)
+    assert "convolution" in text
+    assert "reduce-window" not in text
+    assert "select-and-scatter" not in text
