@@ -10,11 +10,13 @@ epochs), `run_rounds(5)` twice and `evaluate` once, with the identity wire
 and with int8.  Phase B, federated LM at full width: the llama-100m preset
 of `examples/train_lm.py` (12 x 768, 32k vocab, f32) through
 `fed.distributed.make_round` on `fed_mesh(1, 1)` for 3 rounds, K=2, batch
-8, seq 256, with the identity wire and with lowrank r=16.  Every phase
-checks that its compiled program holds the Mosaic kernels
-(`tpu_custom_call`), that loss, state and params are finite, and that the
-params moved; phase B also checks that no round's `agg_norm` grows to ten
-times the first round's (a diverging codec stays finite for a while).
+8, seq 256, with the identity wire and with lowrank r=16.  Phase A checks
+that its compiled program holds the Mosaic kernels (`tpu_custom_call`);
+the LM round's one-epoch client pass streams its gradients and calls
+none.  Every phase checks that loss, state and params are finite, and
+that the params moved; phase B also checks that no round's `agg_norm`
+grows to ten times the first round's (a diverging codec stays finite for
+a while).
 
 `--four-chips` runs, and compares, only what exists across chips: phase A
 on `cohort_mesh(4)` against one device, llama-100m on `fed_mesh(4, 1)`
@@ -253,7 +255,6 @@ def phase_b(codec, dev, rounds=3, **kw):
     t = time.perf_counter()
     compiled = round_fn.lower(params, state, *s["draw"](0)).compile()
     t_compile = time.perf_counter() - t
-    check_kernels(compiled.as_text(), f"phase B {codec}")
     times, norms = [], []
     for r in range(rounds):
         (params, state, m), t = timed(round_fn, params, state, *s["draw"](r))
@@ -347,7 +348,6 @@ def parity_fed_mesh_4x1(devs, cfg=None, lm=LM):
     args = s["draw"](0)
     ref, f_client = lm_reference(s, args)
     compiled = s["round_fn"].lower(s["params"], s["state"], *args).compile()
-    check_kernels(compiled.as_text(), "fed_mesh(4,1)")
     f_dev = flops(compiled)
     (params, state, _), t = timed(s["round_fn"], s["params"], s["state"],
                                   *args)
@@ -381,7 +381,6 @@ def parity_fed_mesh_2x2(devs, cfg=None, lm=LM):
         args = s["draw"](0)
         compiled = s["round_fn"].lower(s["params"], s["state"],
                                        *args).compile()
-        check_kernels(compiled.as_text(), f"fed_mesh{shape}")
         (params, state, _), t = timed(s["round_fn"], s["params"],
                                       s["state"], *args)
         check(leaves_finite(params) and leaves_finite(state),

@@ -21,9 +21,11 @@ class ArchConfig:
     vocab: int
     head_dim: int | None = None      # default: d_model // n_heads
     rope_theta: float = 10000.0
+    norm_eps: float = 1e-6           # RMSNorm epsilon (phi3 publishes 1e-5)
 
     # -- attention pattern ---------------------------------------------------
-    sliding_window: int | None = None     # gemma2 local layers
+    sliding_window: int | None = None     # gemma2 local layers; every
+                                          # layer of a uniform model (phi3)
     local_global_period: int | None = None  # gemma2: 1 local + 1 global per pair
     attn_chunk: int | None = None         # llama4 chunked local attention
     global_period: int | None = None      # every Nth layer full/global

@@ -78,6 +78,26 @@ def _microbatch_grads(task: Task, params, batches):
     return jax.vmap(lambda mb: jax.grad(task.loss)(params, mb))(batches)
 
 
+def _streamed_mean_grad(task: Task, params, batches):
+    """The K microbatch gradients at fixed params, one after another:
+    -> (gbar = mean_i g_i in f32, S2 = sum_i ||g_i||^2).
+
+    Holds a running f32 sum in place of the (K, N) gradient stack, so
+    one microbatch's activations and gradient are live beside it.
+    Unrolled like `_sgd_epoch`; the optimization barrier makes
+    microbatch i+1's pass wait for microbatch i's sum, so XLA cannot
+    interleave the K passes and hold their activations at once."""
+    g_sum, s2 = None, jnp.zeros((), jnp.float32)
+    for k in range(_k_of(batches)):
+        mb = jax.tree.map(lambda x: x[k], batches)
+        g = jax.tree.map(lambda x: x.astype(jnp.float32),
+                         jax.grad(task.loss)(params, mb))
+        s2 = s2 + tree_norm_sq(g)
+        g_sum = g if g_sum is None else jax.tree.map(jnp.add, g_sum, g)
+        params, g_sum, s2 = jax.lax.optimization_barrier((params, g_sum, s2))
+    return tree_scale(g_sum, 1.0 / _k_of(batches)), s2
+
+
 def _sgd_epoch(task: Task, params, batches, lr, grad_tx=None):
     """One pass of sequential SGD over the K microbatches.
 
@@ -178,12 +198,16 @@ def fedncv_client(mc: MethodConfig, task: Task, params, cstate, batches, key):
     with the reshaped gradients, and uploads the expectation gradient plus the
     two sufficient statistics the server needs to adapt alpha_u
     (DESIGN.md §1.2 — the whole RLOO pass costs 2 extra scalars).
+
+    One local epoch needs no reshaped unit: mean_i (g_i - a c_i) =
+    (1 - a) gbar, so the gradients stream through a running sum and sum
+    of squares (`_streamed_mean_grad`) and no (K, N) stack is formed.
     """
     del key
     alpha = cstate["alpha"]
-    g_stack = _microbatch_grads(task, params, batches)
 
     if mc.local_epochs > 1:
+        g_stack = _microbatch_grads(task, params, batches)
         # Multi-step variant: apply RLOO-reshaped gradients sequentially.
         _, stats, reshaped = cv.client_pass_flat(g_stack, alpha,
                                                  want_reshaped=True)
@@ -208,8 +232,11 @@ def fedncv_client(mc: MethodConfig, task: Task, params, cstate, batches, key):
         grad = tree_axpy(1.0, msg, base)
         grad = tree_scale(grad, 0.5)   # average drift + final reshaped grad
     else:
-        # Single fused pass: message == mean_i (g_i - a c_i) = (1-a) gbar.
-        grad, stats, _ = cv.client_pass_flat(g_stack, alpha)
+        mean, s2 = _streamed_mean_grad(task, params, batches)
+        stats = cv.ClientCVStats(mean,
+                                 jnp.asarray(_k_of(batches), jnp.float32),
+                                 tree_norm_sq(mean), s2)
+        grad = cv.client_message(stats, jnp.asarray(alpha, jnp.float32))
 
     aux = dict(mean_norm_sq=stats.mean_norm_sq, sum_norm_sq=stats.sum_norm_sq,
                k=stats.k, alpha=alpha)

@@ -31,12 +31,13 @@ def group_size(cfg: ArchConfig) -> int:
 
 
 def member_kind(cfg: ArchConfig, j: int) -> str:
-    """Attention flavor of group member j: 'full' | 'local' | 'chunked'."""
+    """Attention flavor of group member j: 'full' | 'local' | 'chunked'.
+    A uniform model with a sliding window (phi3) windows every layer."""
     if cfg.local_global_period:
         return "local" if j % 2 == 0 else "full"
     if cfg.global_period:
         return "full" if j == cfg.global_period - 1 else "chunked"
-    return "full"
+    return "local" if cfg.sliding_window else "full"
 
 
 def _attn_spec(cfg: ArchConfig) -> L.AttnParamsSpec:
@@ -98,9 +99,9 @@ def _member_attn(cfg: ArchConfig, p, x, positions, j):
 
 
 def _layer_body(cfg: ArchConfig, p_j, x, positions, j):
-    h = L.rmsnorm(x, p_j["attn_norm"])
+    h = L.rmsnorm(x, p_j["attn_norm"], cfg.norm_eps)
     x = x + _member_attn(cfg, p_j, h, positions, j)
-    h = L.rmsnorm(x, p_j["ffn_norm"])
+    h = L.rmsnorm(x, p_j["ffn_norm"], cfg.norm_eps)
     x = x + L.swiglu(p_j, h)
     return L.shard_residual(x)
 
@@ -127,7 +128,7 @@ def forward(cfg: ArchConfig, params, tokens):
         # unrolled depth loop (static indexing): see ArchConfig.scan_layers
         for i in range(jax.tree.leaves(xs)[0].shape[0]):
             x, _ = body(x, jax.tree.map(lambda t: t[i], xs))
-    x = L.rmsnorm(x, params["final_norm"])
+    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     unembed = params.get("unembed")
     if unembed is None:
         unembed = params["embed"].T
@@ -204,20 +205,20 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos):
         for j in range(g):
             p_j = jax.tree.map(lambda t: t[j], p_group)
             ck, cv = cache_group[f"m{j}"]["k"], cache_group[f"m{j}"]["v"]
-            h = L.rmsnorm(x, p_j["attn_norm"])
+            h = L.rmsnorm(x, p_j["attn_norm"], cfg.norm_eps)
             out, ck, cv = L.decode_attention_block(
                 p_j, h, ck, cv, pos, spec,
                 mode=_member_mode(cfg, j, cache_len),
                 softcap=cfg.softcap, rope_theta=cfg.rope_theta)
             x = x + out
-            h = L.rmsnorm(x, p_j["ffn_norm"])
+            h = L.rmsnorm(x, p_j["ffn_norm"], cfg.norm_eps)
             x = x + L.swiglu(p_j, h)
             new_cache[f"m{j}"] = dict(k=ck, v=cv)
         return x, new_cache
 
     x, new_cache = jax.lax.scan(body, x, (_group_xs(cfg, params["layers"]),
                                           cache))
-    x = L.rmsnorm(x, params["final_norm"])
+    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     unembed = params.get("unembed")
     if unembed is None:
         unembed = params["embed"].T

@@ -188,3 +188,63 @@ def test_lenet_microbatch_grads_compile_without_window_ops(one_chip):
     assert "convolution" in text
     assert "reduce-window" not in text
     assert "select-and-scatter" not in text
+
+
+def test_phi3_silo_round_fits_one_v5e(one_chip, monkeypatch):
+    """One round of the `phi3-mini-silo-1chip` cell at Phi-3-mini's
+    published widths (4 of 32 layers, untied head, 6.5e8 f32 parameters;
+    K=2 microbatches of 2 x 1024 tokens) through `make_round` on a (1, 1)
+    mesh of one described v5e: it fits the chip's 16 GiB.  The one-epoch
+    client pass streams its microbatch gradients, so no (K, N) stack and
+    no `rloo_combine` is in the program (stacked, the compile asked for
+    22.6 GB)."""
+    import repro.kernels
+    # the program's TPU branch, as on the chip: Mosaic kernels, not the
+    # jnp fallbacks the CPU backend picks
+    monkeypatch.setattr(repro.kernels, "default_interpret", lambda: False)
+    root = os.path.join(os.path.dirname(__file__), "..")
+    sys.path.insert(0, root)
+    from chipbench import harness
+    from chipbench.drivers.lm_round import arch_config
+    from chipbench.reference import dense_lm as ref_model
+    from repro.fed.api import get_method
+    from repro.fed.distributed import init_distributed_state, make_round
+    from repro.fed.methods import MethodConfig, Task
+    from repro.models import api
+    from repro.sharding import make_mesh
+
+    base = os.path.join(root, "chipbench")
+    cfg = harness.load_json(base, "configs", "phi3-mini-4k-4l.json")
+    t = harness.load_json(base, "traffic", "silo-2x2x1024.json")
+    m = harness.load_json(base, "workloads",
+                          "phi3-mini-silo-1chip.json")["method"]
+    arch = arch_config(cfg)
+    mesh = make_mesh((1, 1), ("cohort", "model"),
+                     devices=list(one_chip.device_set))
+    rep, csh = NamedSharding(mesh, P()), NamedSharding(mesh, P("cohort"))
+    mc = MethodConfig(name="fedncv", ncv_alpha0=m["alpha0"],
+                      ncv_alpha_lr=m["alpha_lr"], ncv_beta=m["beta"])
+    task = Task(loss=lambda p, b: api.loss(arch, p, b))
+    round_fn = make_round("fedncv", task, mesh, mc, server_lr=m["server_lr"])
+    shapes = jax.eval_shape(lambda: ref_model.init(cfg, jax.random.key(0)))
+    state = jax.eval_shape(lambda: init_distributed_state(
+        get_method("fedncv"), shapes, task, mc, n_clients=t["silos"]))
+
+    def sds(tree, sharding):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    toks = jax.ShapeDtypeStruct((t["silos"], t["k_micro"], t["batch"],
+                                 t["seq"]), jnp.int32, sharding=csh)
+    with jax.default_matmul_precision(cfg["matmul_precision"]):
+        compiled = round_fn.lower(
+            sds(shapes, rep), sds(state, csh),
+            dict(tokens=toks, labels=toks),
+            jax.ShapeDtypeStruct((t["silos"],), jnp.float32, sharding=csh),
+            jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+    assert mem.argument_size_in_bytes > 2.5e9      # the 6.5e8 f32 params
+    assert total < 16 * 2 ** 30, total
+    assert "rloo_combine" not in compiled.as_text()
