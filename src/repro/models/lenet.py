@@ -47,11 +47,40 @@ def init(cfg: LeNetConfig, key):
 HEAD_KEYS = ("head", "bh")          # personalization split (FedRep/FedPer)
 
 
+PATCH_MAX = 128    # widest patch (kh * kw * cin) taken as a patch matmul
+
+
 def _conv(x, w, b):
-    out = jax.lax.conv_general_dilated(
-        x, w, window_strides=(1, 1), padding="VALID",
+    """VALID stride-1 convolution of (B, H, W, Cin) by an HWIO kernel,
+    plus bias.
+
+    A layer whose patch (kh * kw * cin) fits one 128-wide lane tile runs
+    as a patch matmul: its patches, taken by a convolution with a
+    constant one-hot kernel (feature (i, j, c) of a patch is the input at
+    offset (i, j), channel c: the order of `w.reshape(-1, cout)`),
+    against the (kh * kw * cin, cout) kernel.  Under the client pass's
+    vmaps over the cohort and the K microbatches, the one-hot kernel is
+    the same for every group, so the patch convolution only widens its
+    batch, and every product with `w` is a dot with the (client,
+    microbatch) groups as its batch dimension.  `lax.conv_general_dilated`
+    would become a convolution grouped over them, with a few channels on
+    the TPU's lane axis: in the paper protocol's round on a TPU v5e,
+    conv1's grouped weight gradient (3 input channels) was the largest op,
+    a quarter of the round.  A wider patch keeps the convolution: conv2's
+    (150 wide) would pad to two lane tiles, and its grouped gradient (6
+    input channels) is not among the round's ten largest ops.
+    """
+    kh, kw, cin, cout = w.shape
+    k = kh * kw * cin
+    if k > PATCH_MAX:
+        return jax.lax.conv_general_dilated(
+            x, w, window_strides=(1, 1), padding="VALID",
+            dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
+    eye = jnp.eye(k, dtype=x.dtype).reshape(kh, kw, cin, k)
+    p = jax.lax.conv_general_dilated(
+        x, eye, window_strides=(1, 1), padding="VALID",
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    return out + b
+    return p @ w.reshape(k, cout) + b
 
 
 def _corners(x):

@@ -1,6 +1,8 @@
-"""LeNet-5's max-pool: the elementwise 2x2 form with its first-max VJP
-against `lax.reduce_window` and the `select_and_scatter_add` gradient JAX
-derives for it, bit for bit, ties included."""
+"""LeNet-5's layers against the XLA ops they stand in for: the max-pool's
+elementwise 2x2 form with its first-max VJP against `lax.reduce_window`
+and the `select_and_scatter_add` gradient JAX derives for it, bit for
+bit, ties included; the convolution's patch matmul against
+`lax.conv_general_dilated`, forward and per-group gradients."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -92,3 +94,50 @@ def test_lenet_grad_matches_reduce_window_pool(monkeypatch):
         scale = float(jnp.max(jnp.abs(ref[name])))
         err = float(jnp.max(jnp.abs(new[name] - ref[name])))
         assert err <= 1e-6 * max(scale, 1.0), (name, err, scale)
+
+
+# one microbatch's (B, H, W, C) input and the layer's output channels
+CONV_SHAPES = {"conv1": ((16, 32, 32, 3), 6), "conv2": ((16, 14, 14, 6), 16),
+               "gray": ((16, 28, 28, 1), 6)}
+
+
+def lax_conv(x, w, b):
+    return jax.lax.conv_general_dilated(
+        x, w, window_strides=(1, 1), padding="VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
+
+
+@pytest.mark.parametrize("params", ["shared", "per_client"])
+@pytest.mark.parametrize("layer", sorted(CONV_SHAPES))
+def test_conv_matches_conv_general_dilated(layer, params):
+    """`lenet._conv` under the client pass's two vmaps (cohort, then K
+    microbatches): the forward and each group's weight and bias gradient
+    match `lax.conv_general_dilated` at `highest` precision.  "shared"
+    parameters are the first local epoch's (one set for the cohort),
+    "per_client" the second's (one set per client)."""
+    (b, h, w, cin), cout = CONV_SHAPES[layer]
+    kx, kw, kb, kg = jax.random.split(jax.random.PRNGKey(2), 4)
+    lead_p = LEAD[:1] if params == "per_client" else ()
+    x = jax.random.normal(kx, LEAD + (b, h, w, cin))
+    wt = jax.random.normal(kw, lead_p + (5, 5, cin, cout)) / np.sqrt(
+        25 * cin)
+    bias = jax.random.normal(kb, lead_p + (cout,))
+    # cotangent of the output, scaled so each gradient is of order one
+    g = jax.random.normal(kg, LEAD + (b, h - 4, w - 4, cout)) / np.sqrt(
+        b * (h - 4) * (w - 4))
+
+    def run(conv):
+        def one(wt, bias, x, g):
+            y, vjp = jax.vjp(lambda w_, b_: conv(x, w_, b_), wt, bias)
+            return (y,) + vjp(g)
+        p_ax = 0 if params == "per_client" else None
+        f = jax.vmap(jax.vmap(one, (None, None, 0, 0)), (p_ax, p_ax, 0, 0))
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(f)(wt, bias, x, g)
+
+    got, ref = run(lenet._conv), run(lax_conv)
+    for what, a, r in zip(("forward", "d_weight", "d_bias"), got, ref):
+        assert a.shape == r.shape == LEAD + r.shape[2:], what
+        err = float(jnp.max(jnp.abs(a - r)))
+        scale = float(jnp.max(jnp.abs(r)))
+        assert err <= 1e-5 * max(scale, 1.0), (what, err, scale)

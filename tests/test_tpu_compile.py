@@ -165,8 +165,16 @@ def test_lenet_microbatch_grads_compile_without_window_ops(one_chip):
     """The paper protocol's client pass, the per-microbatch LeNet-5
     gradients vmapped over the cohort (10 clients, K=4 microbatches of 16
     cifar10 images), compiles for the v5e with its max-pools as fused
-    elementwise ops: no `reduce-window` forward, no `select-and-scatter`
-    backward (both are layout-bound on the TPU)."""
+    elementwise ops (no `reduce-window` forward, no `select-and-scatter`
+    backward) and conv1 as a patch matmul: its weight gradient is no
+    convolution grouped (`batch_group_count` or `feature_group_count`
+    above 1) over the 40 (client, microbatch) groups with 3 channels a
+    group on the lane axis.  All of these are layout-bound on the TPU.
+    The TPU compiler rewrites a grouped convolution into an ungrouped one
+    over a dilated window, so the groups are looked for in the module
+    handed to it as well as in the compiled one.  conv2 (150-wide
+    patches) keeps its convolution, whose weight gradient is the one
+    grouped convolution handed over."""
     from repro.fed.methods import Task, _microbatch_grads
     from repro.models import lenet
     cfg = lenet.LeNetConfig(n_classes=10, image_size=32, channels=3)
@@ -182,10 +190,15 @@ def test_lenet_microbatch_grads_compile_without_window_ops(one_chip):
     batches = {"images": sds((m, k, 16, 32, 32, 3)),
                "labels": sds((m, k, 16), jnp.int32)}
     with jax.default_matmul_precision("default"):
-        text = compiled_text(
-            jax.vmap(lambda p, b: _microbatch_grads(task, p, b), (None, 0)),
-            params, batches)
-    assert "convolution" in text
+        lowered = jax.jit(
+            jax.vmap(lambda p, b: _microbatch_grads(task, p, b), (None, 0))
+        ).lower(params, batches)
+        handed = lowered.as_text(dialect="hlo")
+        text = lowered.compile().as_text()
+    grouped = [ln for ln in handed.splitlines()
+               if re.search(r"_group_count=(?!1\b)\d+", ln)]
+    assert grouped and all("f32[5,5,6," in ln for ln in grouped), grouped
+    assert not re.search(r"_group_count=(?!1\b)\d+", text)
     assert "reduce-window" not in text
     assert "select-and-scatter" not in text
 
